@@ -28,8 +28,8 @@ def _args(op, key):
                                 -127, 128, jnp.int32).astype(jnp.int8)
         return (r(1, (64, 128)), wq, jnp.abs(r(3, (256,))) * 0.02), {}
     if op == "gae":
-        d = jax.random.bernoulli(jax.random.fold_in(key, 3), 0.1, (64, 128))
-        return (r(1, (64, 128)), r(2, (64, 128)), d, r(4, (64,)),
+        d = jax.random.bernoulli(jax.random.fold_in(key, 3), 0.1, (128, 64))
+        return (r(1, (128, 64)), r(2, (128, 64)), d, r(4, (64,)),
                 0.99, 0.95), {}
     if op == "ssd":
         return (r(1, (2, 128, 4, 32), 0.5),
@@ -73,4 +73,6 @@ def main(include_interpret: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import setup_compile_cache
+    setup_compile_cache()
     main()

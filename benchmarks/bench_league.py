@@ -86,9 +86,10 @@ def main():
     ap.add_argument("--out", default="BENCH_league.json")
     args = ap.parse_args()
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))), "src"))
+    from repro.utils.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     # the paper's small-env regime: per-match compute is tiny, so dispatch
     # count is the cost — exactly where the fused pool launch pays off

@@ -80,4 +80,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import setup_compile_cache
+    setup_compile_cache()
     main()

@@ -63,8 +63,7 @@ class AuditResult:
 # jaxpr walking
 
 def _subjaxprs(params: dict):
-    from jax.core import Jaxpr
-    from jax.extend.core import ClosedJaxpr  # jax >= 0.4.x
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def leaves(v):
         if isinstance(v, (ClosedJaxpr,)):

@@ -59,8 +59,8 @@ def _kernel_cases(mode: str) -> Dict[str, tuple]:
                 _rand(k(5), (B, 16, 1, 8), scale=0.5))
 
     def gae(B):
-        return (_rand(k(1), (B, 32)), _rand(k(2), (B, 32)),
-                jax.random.bernoulli(k(3), 0.1, (B, 32)),
+        return (_rand(k(1), (32, B)), _rand(k(2), (32, B)),
+                jax.random.bernoulli(k(3), 0.1, (32, B)),
                 _rand(k(4), (B,)), 0.99, 0.95)
 
     def quant(M):
@@ -150,7 +150,6 @@ def _host_trajectory(em, pol, dist, tcfg, params, recurrent: bool):
 
 
 def audit_engine_tiers() -> List[AuditResult]:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.rl.engine import _scan_launch
     from repro.rl.learner import make_ocean_learn
@@ -167,9 +166,9 @@ def audit_engine_tiers() -> List[AuditResult]:
     # shard_map tier: same launch through the mesh wrapper (1-device CPU
     # mesh in CI; the program structure — collectives, specs — is identical)
     sm, *_ = _engine_fixture("shard_map")
-    fn = shard_map(_scan_launch(sm._update, 1), mesh=sm.mesh,
-                   in_specs=(P(), sm._rc_spec, P()),
-                   out_specs=(P(), sm._rc_spec, P()), check_rep=False)
+    fn = jax.shard_map(_scan_launch(sm._update, 1), mesh=sm.mesh,
+                       in_specs=(P(), sm._rc_spec, P()),
+                       out_specs=(P(), sm._rc_spec, P()), check_vma=False)
     out.append(audit_fn(fn, (sm.ts, sm.rc, key),
                         name="engine:shard_map:launch",
                         donate_argnums=(0, 1)))

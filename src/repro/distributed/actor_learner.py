@@ -34,9 +34,14 @@ ctrl bytes, no locks):
 This module is the spawn-worker entrypoint, so its import chain must stay
 jax-free (same rule as core/shm.py): actors import jax themselves after the
 fork guard, and all learner-side jax use is deferred to method bodies.
+
+One process per chip: actors run their rollouts on the CPU on purpose. An
+accelerator belongs to one process at a time and the learner holds it, so
+each actor pins ``JAX_PLATFORMS=cpu`` before its own ``import jax``.
 """
 from __future__ import annotations
 
+import os
 import pickle
 import sys
 import time
@@ -65,6 +70,8 @@ A_ERR = 2          # actor raised; error row holds the message
 A_EXIT = 3         # clean exit after stop
 
 INFO_KEYS = ("score", "episode_return", "episode_length", "valid")
+
+ACTOR_PLATFORM = "cpu"   # actors never touch the learner's accelerator
 
 
 class ActorError(RuntimeError):
@@ -242,6 +249,8 @@ def actor_main(cfg: ActorConfig) -> None:
         raise RuntimeError(
             "actor started with jax already imported — it was forked, not "
             "spawned. AsyncRollouts must use the 'spawn' start method")
+    # the learner holds the accelerator; actors run on the CPU
+    os.environ["JAX_PLATFORMS"] = ACTOR_PLATFORM
     import jax
     import jax.numpy as jnp
     from repro.core.vector import VecEnv
@@ -657,6 +666,9 @@ class AsyncRollouts:
 
     def stats(self) -> dict:
         out = {
+            # actors run on the CPU on purpose: only the learner holds the
+            # accelerator (one process per chip)
+            "actor_platform": ACTOR_PLATFORM,
             "assign": self._v["assign"].tolist(),
             "epoch": self._v["epoch"].tolist(),
             "heartbeats": self._v["hbeat"].tolist(),

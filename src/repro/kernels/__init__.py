@@ -1,9 +1,8 @@
 """Kernel layer: Pallas TPU kernels behind a dispatch registry.
 
 - ``ops``      — public op functions (what models/rl call)
-- ``dispatch`` — registry: (op, platform, JAX version) → implementation,
-  env/scoped overrides, autotune
-- ``compat``   — shims over ``jax.experimental.pallas`` API drift
+- ``dispatch`` — registry: (op, platform) → implementation, env/scoped
+  overrides, autotune
 - ``ref``      — pure-jnp oracles (correctness ground truth)
 - one module per Pallas kernel (flash_attention, flash_decode,
   quant_matmul, gae_scan, ssd, pack)

@@ -10,7 +10,7 @@ Each op (``flash_attention``, ``flash_decode``, ``quant_matmul``,
                        (``interpret`` is accepted as an alias)
   ``pallas``           compiled Pallas — TPU default
 
-Selection per (op, platform, JAX version), highest precedence first:
+Selection per (op, platform), highest precedence first:
 
   1. explicit ``mode=`` at the call site
   2. a ``dispatch.using(mode)`` scope — replaces threading ``kernel=``
@@ -21,10 +21,10 @@ Selection per (op, platform, JAX version), highest precedence first:
   5. the cached :func:`autotune` winner for (op, platform)
   6. platform default — ``pallas`` on TPU, ``ref`` elsewhere
 
-Impls that require a TPU or a minimum JAX version are excluded from
-:func:`available` on hosts that can't run them, so graceful degradation
-(ref math on CPU, interpret-mode Pallas in CI, compiled Pallas on TPU)
-is a property of the registry, not of each call site.
+Compiled Pallas requires a TPU and is excluded from :func:`available`
+elsewhere, so the platform default (ref math on CPU, compiled Pallas on
+TPU) is a property of the registry, not of each call site. Nothing falls
+back silently: an impl that fails to lower or run raises.
 """
 from __future__ import annotations
 
@@ -36,8 +36,6 @@ from dataclasses import dataclass
 from typing import Callable, Dict, Tuple
 
 import jax
-
-from repro.kernels import compat
 
 OPS = ("flash_attention", "flash_decode", "quant_matmul", "gae", "ssd",
        "pack")
@@ -61,7 +59,6 @@ class Impl:
     name: str
     fn: Callable
     requires_tpu: bool = False
-    min_jax: Tuple[int, ...] = ()
 
 
 _REGISTRY: Dict[str, Dict[str, Impl]] = {}
@@ -69,12 +66,10 @@ _AUTOTUNED: Dict[Tuple[str, str], str] = {}
 _TLS = threading.local()
 
 
-def register(op: str, name: str, *, requires_tpu: bool = False,
-             min_jax: tuple = ()):
+def register(op: str, name: str, *, requires_tpu: bool = False):
     """Decorator: register ``fn`` as implementation ``name`` of ``op``."""
     def deco(fn):
-        _REGISTRY.setdefault(op, {})[name] = Impl(
-            op, name, fn, requires_tpu, tuple(min_jax))
+        _REGISTRY.setdefault(op, {})[name] = Impl(op, name, fn, requires_tpu)
         return fn
     return deco
 
@@ -105,11 +100,7 @@ def platform() -> str:
 
 
 def _usable(impl: Impl, plat: str) -> bool:
-    if impl.requires_tpu and plat != "tpu":
-        return False
-    if impl.min_jax and compat.jax_version() < impl.min_jax:
-        return False
-    return True
+    return plat == "tpu" or not impl.requires_tpu
 
 
 def available(op: str, plat: str = None) -> tuple:
@@ -190,8 +181,8 @@ def autotune(op: str, *args, impls: tuple = None, iters: int = 3,
 
     Returns ``({impl: calls_per_second}, best)`` and caches the winner so
     subsequent ``mode=None/"auto"`` dispatch on this platform uses it
-    (cleared with :func:`clear_autotune`). Impls that fail to run are
-    skipped — a Pallas kernel that can't lower here simply loses."""
+    (cleared with :func:`clear_autotune`). An impl that fails to lower or
+    run raises: a broken kernel is a fault to fix, not a loser."""
     _check_op(op)
     results = {}
     names = tuple(_ALIASES.get(n, n) for n in impls) if impls \
@@ -201,19 +192,14 @@ def autotune(op: str, *args, impls: tuple = None, iters: int = 3,
             raise KeyError(f"{op}: no implementation {name!r}; "
                            f"have {implementations(op)}")
         fn = _REGISTRY[op][name].fn
-        try:
-            for _ in range(warmup):
-                jax.block_until_ready(fn(*args, **kwargs))  # repro: noqa[HOST-SYNC] — autotune warmup (deliberate sync)
-            t0 = time.perf_counter()
-            out = None
-            for _ in range(iters):
-                out = fn(*args, **kwargs)
-            jax.block_until_ready(out)  # repro: noqa[HOST-SYNC] — autotune timing barrier (deliberate)
-            results[name] = iters / (time.perf_counter() - t0)
-        except Exception:
-            continue
-    if not results:
-        raise RuntimeError(f"autotune: no implementation of {op!r} ran")
+        for _ in range(warmup):
+            jax.block_until_ready(fn(*args, **kwargs))  # repro: noqa[HOST-SYNC] — autotune warmup (deliberate sync)
+        t0 = time.perf_counter()
+        out = None
+        for _ in range(iters):
+            out = fn(*args, **kwargs)
+        jax.block_until_ready(out)  # repro: noqa[HOST-SYNC] — autotune timing barrier (deliberate)
+        results[name] = iters / (time.perf_counter() - t0)
     best = max(results, key=results.get)
     _AUTOTUNED[(op, platform())] = best
     return results, best

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 NEG_INF = -1e30
 
 
@@ -110,7 +108,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
             pltpu.VMEM((block_q, 1), jnp.float32),    # running denom
             pltpu.VMEM((block_q, hd), jnp.float32),   # output accumulator
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

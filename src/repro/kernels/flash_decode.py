@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 NEG_INF = -1e30
 
 
@@ -98,7 +96,7 @@ def flash_decode(q, k, v, length, *, block_s: int = 512,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lens, qg, kh, vh)

@@ -18,24 +18,18 @@ platform default. ``mode=None`` and ``mode="auto"`` both mean
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 
 from repro.kernels import dispatch
+from repro.kernels import flash_attention as _fa
+from repro.kernels import flash_decode as _fd
+from repro.kernels import gae_scan as _gae
+from repro.kernels import pack as _pack
+from repro.kernels import quant_matmul as _qmm
 from repro.kernels import ref as _ref
-
-# The Pallas kernel modules are optional: a JAX build without pallas (or
-# with incompatible API drift beyond what kernels.compat shims) still
-# serves every op through ``ref``/``chunked``.
-try:
-    from repro.kernels import flash_attention as _fa
-    from repro.kernels import ssd as _ssd
-    from repro.kernels import gae_scan as _gae
-    from repro.kernels import pack as _pack
-    from repro.kernels import quant_matmul as _qmm
-    from repro.kernels import flash_decode as _fd
-    HAS_PALLAS_KERNELS = True
-except ImportError:   # pragma: no cover — exercised only without pallas
-    HAS_PALLAS_KERNELS = False
+from repro.kernels import ssd as _ssd
 
 
 # "KERNEL_" named scopes mark regions whose HLO stands in for a Pallas kernel
@@ -114,40 +108,63 @@ dispatch.register("flash_decode", dispatch.CHUNKED)(_fd_ref)
 
 # -- Pallas backends (interpret + compiled share one body per op) -------------
 
-if HAS_PALLAS_KERNELS:
+def _pallas_pair(op, fn):
+    """Register ``fn(*a, interpret=...)`` as both the interpret-mode CI
+    backend and the compiled TPU backend of ``op``."""
+    dispatch.register(op, dispatch.INTERPRET)(
+        functools.partial(fn, interpret=True))
+    dispatch.register(op, dispatch.PALLAS, requires_tpu=True)(
+        functools.partial(fn, interpret=False))
 
-    def _pallas_pair(op, fn):
-        """Register ``fn(*a, interpret=...)`` as both the interpret-mode CI
-        backend and the compiled TPU backend of ``op``."""
-        import functools
-        dispatch.register(op, dispatch.INTERPRET)(
-            functools.partial(fn, interpret=True))
-        dispatch.register(op, dispatch.PALLAS, requires_tpu=True)(
-            functools.partial(fn, interpret=False))
 
-    _pallas_pair("flash_attention",
-                 lambda q, k, v, *, causal=True, block_q=128, block_k=128,
-                 interpret: _fa.flash_attention(
-                     q, k, v, causal=causal, block_q=block_q,
-                     block_k=block_k, interpret=interpret))
-    _pallas_pair("ssd",
-                 lambda x, dt, A, B_, C, *, chunk=128, interpret:
-                 _ssd.ssd(x, dt, A, B_, C, chunk=chunk, interpret=interpret))
-    _pallas_pair("gae",
-                 lambda rewards, values, dones, last_value, gamma, lam, *,
-                 block_t=128, interpret: _gae.gae(
-                     rewards, values, dones, last_value, gamma, lam,
-                     block_t=block_t, interpret=interpret))
-    _pallas_pair("pack",
-                 lambda leaves, *, interpret:
-                 _pack.pack(leaves, interpret=interpret))
-    _pallas_pair("quant_matmul",
-                 lambda x, w_q, scale, *, interpret:
-                 _qmm.quant_matmul(x, w_q, scale, interpret=interpret))
-    _pallas_pair("flash_decode",
-                 lambda q, k, v, length, *, block_s=512, interpret:
-                 _fd.flash_decode(q, k, v, length, block_s=block_s,
-                                  interpret=interpret))
+def _grad_via(kernel, twin, *args):
+    """``kernel(*args)`` forward, differentiated through its jnp ``twin``:
+    ``pallas_call`` has no autodiff rule, and the models train through
+    flash attention and SSD. The backward recomputes the twin from the
+    saved inputs."""
+    @jax.custom_vjp
+    def f(*a):
+        return kernel(*a)
+
+    def fwd(*a):
+        return kernel(*a), a
+
+    def bwd(a, g):
+        return jax.vjp(twin, *a)[1](g)
+
+    f.defvjp(fwd, bwd)
+    return f(*args)
+
+
+_pallas_pair("flash_attention",
+             lambda q, k, v, *, causal=True, block_q=128, block_k=128,
+             interpret: _grad_via(
+                 functools.partial(_fa.flash_attention, causal=causal,
+                                   block_q=block_q, block_k=block_k,
+                                   interpret=interpret),
+                 functools.partial(_ref.flash_attention_chunked,
+                                   causal=causal), q, k, v))
+_pallas_pair("ssd",
+             lambda x, dt, A, B_, C, *, chunk=128, interpret: _grad_via(
+                 functools.partial(_ssd.ssd, chunk=chunk,
+                                   interpret=interpret),
+                 functools.partial(_ref.ssd_chunked, chunk=chunk),
+                 x, dt, A, B_, C))
+_pallas_pair("gae",
+             lambda rewards, values, dones, last_value, gamma, lam, *,
+             block_t=128, interpret: _gae.gae(
+                 rewards, values, dones, last_value, gamma, lam,
+                 block_t=block_t, interpret=interpret))
+_pallas_pair("pack",
+             lambda leaves, *, interpret:
+             _pack.pack(leaves, interpret=interpret))
+_pallas_pair("quant_matmul",
+             lambda x, w_q, scale, *, interpret:
+             _qmm.quant_matmul(x, w_q, scale, interpret=interpret))
+_pallas_pair("flash_decode",
+             lambda q, k, v, length, *, block_s=512, interpret:
+             _fd.flash_decode(q, k, v, length, block_s=block_s,
+                              interpret=interpret))
 
 
 # -- public ops ---------------------------------------------------------------
@@ -164,6 +181,7 @@ def ssd(x, dt, A, B_, C, chunk: int = 128, mode: str = None):
 
 def gae(rewards, values, dones, last_value, gamma: float, lam: float,
         mode: str = None, block_t: int = 128):
+    """Time-major: rewards/values/dones (T, B), last_value (B,)."""
     return dispatch.call("gae", rewards, values, dones, last_value,
                          gamma, lam, mode=mode, block_t=block_t)
 

@@ -158,9 +158,9 @@ def ssd_chunked(x, dt, A, B_, C, chunk: int = 128):
 def gae(rewards, values, dones, last_value, gamma: float, lam: float):
     """Generalized advantage estimation, time-major reverse scan oracle.
 
-    rewards/dones: (B, T); values: (B, T); last_value: (B,)
-    done_t marks that the episode ended *at* step t (no bootstrap across it).
-    returns advantages (B, T).
+    rewards/values/dones: (T, B) time-major, the learner's rollout layout;
+    last_value: (B,). done_t marks that the episode ended *at* step t (no
+    bootstrap across it). returns advantages (T, B).
     """
     f32 = jnp.float32
     rewards, values, last_value = (t.astype(f32) for t in
@@ -174,10 +174,9 @@ def gae(rewards, values, dones, last_value, gamma: float, lam: float):
         adv = delta + gamma * lam * nt * adv_next
         return (adv, v), adv
 
-    xs = (jnp.moveaxis(rewards, 1, 0)[::-1], jnp.moveaxis(values, 1, 0)[::-1],
-          jnp.moveaxis(nonterm, 1, 0)[::-1])
-    _, advs = jax.lax.scan(step, (jnp.zeros_like(last_value), last_value), xs)
-    return jnp.moveaxis(advs[::-1], 0, 1)
+    _, advs = jax.lax.scan(step, (jnp.zeros_like(last_value), last_value),
+                           (rewards, values, nonterm), reverse=True)
+    return advs
 
 
 def pack(leaves):

@@ -6,6 +6,7 @@ passed *between* chunks by a cheap rank-preserving recurrence. We tile one
 (head, chunk) per grid cell; the (hd × ds) state lives in VMEM scratch and is
 carried across the sequential chunk dimension of the grid, so the whole
 sequence is processed with one kernel launch and zero HBM state traffic.
+The per-head decay rates ride in SMEM, read by the grid's head index.
 
 Grid: (B*H, num_chunks) — last dim sequential (state carry).
 """
@@ -18,11 +19,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hlast_ref, h_ref, *,
-            chunk: int, num_chunks: int):
+            chunk: int, num_chunks: int, num_heads: int):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
@@ -31,34 +30,42 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hlast_ref, h_ref, *,
 
     x = x_ref[0].astype(jnp.float32)         # (Q, hd)
     dt = dt_ref[0].astype(jnp.float32)       # (Q, 1)
-    A = a_ref[0, 0]                          # scalar decay rate (negative)
+    A = a_ref[pl.program_id(0) % num_heads]  # scalar decay rate (negative)
     B = b_ref[0].astype(jnp.float32)         # (Q, ds)
     C = c_ref[0].astype(jnp.float32)         # (Q, ds)
 
-    dA = dt[:, 0] * A                        # (Q,)
-    cum = jnp.cumsum(dA)                     # inclusive (Q,)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = rows >= cols
+    dA = dt * A                              # (Q, 1)
+    # Mosaic has no cumsum and no (Q, 1) -> (1, Q) transpose: build both
+    # orientations from masked reductions over a (Q, Q) tile instead
+    dt_row = jnp.sum(jnp.where(rows == cols, dt, 0.0), axis=0,
+                     keepdims=True)                                # (1, Q)
+    dA_row = dt_row * A
+    cum = jnp.sum(jnp.where(causal, dA_row, 0.0), axis=1,
+                  keepdims=True)             # (Q, 1) inclusive cumsum
+    cum_row = jnp.sum(jnp.where(rows <= cols, dA, 0.0), axis=0,
+                      keepdims=True)         # (1, Q) the same, on lanes
 
     # Within-chunk (the "duality": a decay-masked attention matmul)
     # L[i,j] = exp(cum_i - cum_j) for i >= j
-    li = cum[:, None] - cum[None, :]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(rows >= cols, jnp.exp(li), 0.0)
+    L = jnp.where(causal, jnp.exp(cum - cum_row), 0.0)
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())))   # (Q,Q)
-    y = jax.lax.dot_general(scores * L * dt[:, 0][None, :], x,
+    y = jax.lax.dot_general(scores * L * dt_row, x,
                             (((1,), (0,)), ((), ())))              # (Q,hd)
 
     # Inter-chunk: contribution of the carried state
-    decay_in = jnp.exp(cum)[:, None]                               # (Q,1)
     h = h_ref[...]                                                 # (hd,ds)
-    y = y + decay_in * jax.lax.dot_general(C, h,
-                                           (((1,), (1,)), ((), ())))
+    y = y + jnp.exp(cum) * jax.lax.dot_general(C, h,
+                                               (((1,), (1,)), ((), ())))
     y_ref[0] = y.astype(y_ref.dtype)
 
     # State update: h' = exp(cum_Q) h + sum_j exp(cum_Q - cum_j) dt_j x_j B_j^T
-    w = (jnp.exp(cum[-1] - cum) * dt[:, 0])[:, None]               # (Q,1)
+    cum_last = cum[chunk - 1:chunk, :]                             # (1,1)
+    w = jnp.exp(cum_last - cum) * dt                               # (Q,1)
     upd = jax.lax.dot_general(x * w, B, (((0,), (0,)), ((), ())))  # (hd,ds)
-    h_ref[...] = jnp.exp(cum[-1]) * h + upd
+    h_ref[...] = jnp.exp(cum_last) * h + upd
 
     @pl.when(ci == num_chunks - 1)
     def _emit_state():
@@ -81,16 +88,15 @@ def ssd(x, dt, A, B_, C, *, chunk: int = 128, interpret: bool = False):
     dth = jnp.moveaxis(dt, 2, 1).reshape(BH, T, 1)
     bh = jnp.moveaxis(B_, 2, 1).reshape(BH, T, ds)
     ch = jnp.moveaxis(C, 2, 1).reshape(BH, T, ds)
-    ah = jnp.tile(A.astype(jnp.float32)[:, None], (Bb, 1))        # (BH, 1)
 
     grid = (BH, nc)
     y, hlast = pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk, num_chunks=nc),
+        functools.partial(_kernel, chunk=chunk, num_chunks=nc, num_heads=H),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, 1), lambda b, c: (b, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, ds), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, ds), lambda b, c: (b, c, 0)),
         ],
@@ -103,10 +109,10 @@ def ssd(x, dt, A, B_, C, *, chunk: int = 128, interpret: bool = False):
             jax.ShapeDtypeStruct((BH, hd, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(xh, dth, ah, bh, ch)
+    )(xh, dth, A.astype(jnp.float32), bh, ch)
 
     y = jnp.moveaxis(y.reshape(Bb, H, T, hd), 1, 2)
     return y, hlast.reshape(Bb, H, hd, ds)

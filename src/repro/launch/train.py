@@ -12,6 +12,94 @@ import argparse
 import os
 
 
+def ocean_trainer(name: str, *, seed: int = 0, log_dir: str = None,
+                  num_shards: int = 1, **tcfg_overrides):
+    """The ``--ocean`` trainer for one env: its preset's policy widths and
+    ``ocean_tcfg`` defaults plus ``tcfg_overrides``. ``num_shards`` is the
+    jit tier's S-block emulation of a data-parallel run (see
+    ``TrainEngine``). Returns ``(Trainer, preset)``."""
+    from repro.configs.ocean import ocean_tcfg, preset
+    from repro.envs.ocean import OCEAN
+    from repro.rl.trainer import Trainer
+    p = preset(name)
+    tcfg = ocean_tcfg(name, **tcfg_overrides)
+    tr = Trainer(OCEAN[name](), tcfg, hidden=p.hidden, recurrent=p.recurrent,
+                 conv=p.conv, seed=seed, log_dir=log_dir,
+                 num_shards=num_shards)
+    return tr, p
+
+
+def train_lm(arch: str, *, smoke: bool = False, mesh: str = "1x1",
+             batch: int = 8, seq: int = 256, steps: int = 100, seed: int = 0,
+             ckpt_dir: str = None, save_every: int = 50,
+             resume: bool = False):
+    """LM-backbone PPO (the ``--arch`` path): ``steps`` train steps of
+    ``arch`` on random token rollouts over a ``DxM`` mesh, through the
+    fault-tolerant loop. ``ckpt_dir=None`` turns checkpointing off.
+    Returns ``(loop, history)``, one dict of float metrics per step."""
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config, get_smoke_config
+    from repro.configs.base import TrainConfig
+    from repro.data.buffer import random_batch
+    from repro.distributed import sharding as shd
+    from repro.distributed.fault import ResilientLoop
+    from repro.launch.mesh import make_mesh
+    from repro.models.params import set_fsdp_axes
+    from repro.models.policy import BackbonePolicy
+    from repro.rl.learner import init_train_state, make_lm_train_step
+
+    shape = tuple(int(x) for x in mesh.split("x"))
+    axes = ("data", "model")[:len(shape)] if len(shape) == 2 \
+        else ("pod", "data", "model")
+    dev_mesh = make_mesh(shape, axes)
+    set_fsdp_axes(tuple(a for a in ("pod", "data") if a in axes))
+    rules = shd.make_rules(dev_mesh)
+    tp = dict(zip(axes, shape)).get("model", 1)
+
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    policy = BackbonePolicy(cfg, tp=tp, kernel="auto")
+    tcfg = TrainConfig(checkpoint_dir=ckpt_dir)
+    key = jax.random.PRNGKey(seed)
+    history = []
+
+    with dev_mesh:
+        state_sh = shd.named(dev_mesh, shd.train_state_pspecs(policy, rules))
+        step = jax.jit(make_lm_train_step(policy, tcfg,
+                                          loss_chunk=min(256, seq)),
+                       out_shardings=(state_sh, None))
+        loop = ResilientLoop(step, ckpt_dir, save_every=save_every,
+                             shardings=state_sh)
+
+        def initial_state():
+            # no name here outlives the call: a step's input and output
+            # states are the only two alive at once (a third copy of
+            # qwen3-0.6b's params and Adam moments overflows a 16 GB chip)
+            state = init_train_state(policy.init(key),
+                                     jnp.dtype(tcfg.optimizer_state_dtype))
+            if resume:
+                state, start = loop.resume_or_init(state)
+                loop.steps_done = start
+                print(f"resumed at step {start}")
+            return state
+
+        def batches():
+            for i in range(steps - loop.steps_done):
+                yield random_batch(cfg, batch, seq,
+                                   jax.random.fold_in(key, 1000 + i))
+
+        def on_metrics(i, m):
+            history.append({k: float(v) for k, v in m.items()})
+            if i % 5 == 0 or i == 1:
+                print(f"step {i:5d} loss {float(m['loss']):+.4f} "
+                      f"kl {float(m['approx_kl']):.4f} "
+                      f"gnorm {float(m['grad_norm']):.2f} "
+                      f"median_step {loop.monitor.median*1e3:.0f}ms")
+
+        loop.run(initial_state(), batches(), on_metrics)
+    return loop, history
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--ocean", default=None,
@@ -99,7 +187,8 @@ def main():
                                    f"{args.devices}")
 
     import jax
-    import jax.numpy as jnp
+    from repro.utils.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.conformance and not args.ocean:
         ap.error("--conformance requires --ocean <name(s)|all>")
@@ -202,8 +291,6 @@ def main():
     if args.ocean:
         from repro import telemetry
         from repro.envs.ocean import OCEAN
-        from repro.rl.trainer import Trainer
-        from repro.configs.ocean import ocean_tcfg, preset
         if args.run_dir:
             telemetry.enable(args.run_dir)
         server = None
@@ -229,18 +316,15 @@ def main():
             else [n.strip() for n in args.ocean.split(",")]
         try:
             for name in names:
-                p = preset(name)
                 backend = args.engine_backend or "jit"
-                tcfg = ocean_tcfg(name, checkpoint_dir=args.ckpt_dir,
-                                  engine_backend=backend,
-                                  updates_per_launch=args.updates_per_launch,
-                                  checkpoint_every=args.save_every,
-                                  metrics_port=(server.port if server
-                                                else 0),
-                                  **async_overrides)
-                tr = Trainer(OCEAN[name](), tcfg, hidden=p.hidden,
-                             recurrent=p.recurrent, conv=p.conv,
-                             seed=args.seed, log_dir=args.run_dir)
+                tr, p = ocean_trainer(
+                    name, seed=args.seed, log_dir=args.run_dir,
+                    checkpoint_dir=args.ckpt_dir, engine_backend=backend,
+                    updates_per_launch=args.updates_per_launch,
+                    checkpoint_every=args.save_every,
+                    metrics_port=(server.port if server else 0),
+                    **async_overrides)
+                tcfg = tr.tcfg
                 if server is not None:
                     # fixed key: replaces the previous env's source, so a
                     # closed engine never lingers as a dead health source
@@ -277,61 +361,13 @@ def main():
                       f"{args.run_dir}")
         return
 
-    # ---- LM backbone PPO ------------------------------------------------------
-    from repro.configs import get_config, get_smoke_config
-    from repro.configs.base import TrainConfig
-    from repro.data.buffer import random_batch
-    from repro.distributed import sharding as shd
-    from repro.distributed.fault import ResilientLoop
-    from repro.launch.mesh import make_mesh
-    from repro.models.params import set_fsdp_axes
-    from repro.models.policy import BackbonePolicy
-    from repro.rl.learner import init_train_state, make_lm_train_step
-
-    shape = tuple(int(x) for x in args.mesh.split("x"))
-    axes = ("data", "model")[:len(shape)] if len(shape) == 2 \
-        else ("pod", "data", "model")
-    mesh = make_mesh(shape, axes)
-    set_fsdp_axes(tuple(a for a in ("pod", "data") if a in axes))
-    rules = shd.make_rules(mesh)
-    tp = dict(zip(axes, shape)).get("model", 1)
-
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    policy = BackbonePolicy(cfg, tp=tp, kernel="auto")
-    tcfg = TrainConfig(checkpoint_dir=args.ckpt_dir)
-    key = jax.random.PRNGKey(args.seed)
-
-    with mesh:
-        state = init_train_state(policy.init(key),
-                                 jnp.dtype(tcfg.optimizer_state_dtype))
-        state_sh = shd.named(mesh, shd.train_state_pspecs(policy, rules))
-        step = jax.jit(make_lm_train_step(policy, tcfg,
-                                          loss_chunk=min(256, args.seq)),
-                       out_shardings=(state_sh, None))
-        loop = ResilientLoop(step, args.ckpt_dir,
-                             save_every=args.save_every,
-                             shardings=state_sh)
-        if args.resume:
-            state, start = loop.resume_or_init(state)
-            loop.steps_done = start
-            print(f"resumed at step {start}")
-
-        def batches():
-            for i in range(args.steps - loop.steps_done):
-                yield random_batch(cfg, args.batch, args.seq,
-                                   jax.random.fold_in(key, 1000 + i))
-
-        def on_metrics(i, m):
-            if i % 5 == 0 or i == 1:
-                print(f"step {i:5d} loss {float(m['loss']):+.4f} "
-                      f"kl {float(m['approx_kl']):.4f} "
-                      f"gnorm {float(m['grad_norm']):.2f} "
-                      f"median_step {loop.monitor.median*1e3:.0f}ms")
-
-        state = loop.run(state, batches(), on_metrics)
+    # ---- LM backbone PPO -----------------------------------------------------
+    loop, _ = train_lm(args.arch, smoke=args.smoke, mesh=args.mesh,
+                       batch=args.batch, seq=args.seq, steps=args.steps,
+                       seed=args.seed, ckpt_dir=args.ckpt_dir,
+                       save_every=args.save_every, resume=args.resume)
     print(f"done: {loop.steps_done} steps, {loop.recoveries} recoveries, "
           f"{loop.monitor.flagged} straggler flags")
-
 
 if __name__ == "__main__":
     main()

@@ -70,7 +70,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import ckpt
@@ -343,9 +342,9 @@ class TrainEngine:
             if self.backend == "shard_map":
                 in_specs = ((P(), self._rc_spec, P(), P()) if sp
                             else (P(), self._rc_spec, P()))
-                fn = shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                               out_specs=(P(), self._rc_spec, P()),
-                               check_rep=False)
+                fn = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                   out_specs=(P(), self._rc_spec, P()),
+                                   check_vma=False)
             self._launches[k] = jax.jit(fn, donate_argnums=(0, 1))
         return self._launches[k]
 

@@ -144,9 +144,9 @@ def make_ocean_learn(policy, tcfg: TrainConfig, dist,
         B_global = B * (S if axis_name is not None else 1)
 
         if adv_fn is None:
-            adv = kops.gae(traj.rewards.T, traj.values.T, traj.dones.T,
+            adv = kops.gae(traj.rewards, traj.values, traj.dones,
                            last_value, tcfg.gamma, tcfg.gae_lambda,
-                           mode=kernel_mode).T                 # (T, B)
+                           mode=kernel_mode)                   # (T, B)
             returns = adv + traj.values
         else:
             adv, returns = adv_fn(ts.params, traj, last_value)
@@ -331,9 +331,10 @@ def make_lm_train_step(policy, tcfg: TrainConfig, total_steps: int = 10_000,
                                  policy.tp, kernel=policy.kernel)
         values = policy._value(params, hidden)              # (B, T)
 
-        adv = kops.gae(batch["rewards"], batch["old_values"],
-                       batch["dones"], batch["last_value"],
-                       tcfg.gamma, tcfg.gae_lambda, mode=gae_mode)
+        # the token batch is (B, T); GAE runs time-major
+        adv = kops.gae(batch["rewards"].T, batch["old_values"].T,
+                       batch["dones"].T, batch["last_value"],
+                       tcfg.gamma, tcfg.gae_lambda, mode=gae_mode).T
         returns = adv + batch["old_values"]
         adv = ppo.normalize_adv(adv, tcfg.norm_adv)
 

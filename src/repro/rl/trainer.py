@@ -61,7 +61,7 @@ class Trainer:
                  recurrent: bool = False, seed: int = 0,
                  kernel_mode: str = None, log_dir: str = None,
                  backend: str = None, updates_per_launch: int = None,
-                 mesh=None, conv: bool = None):
+                 mesh=None, conv: bool = None, num_shards: int = 1):
         from repro.utils.metrics import MetricsLogger
         self.logger = MetricsLogger(log_dir,
                                     run_name=type(env).__name__.lower())
@@ -72,7 +72,8 @@ class Trainer:
                                   key=jax.random.PRNGKey(seed),
                                   backend=backend,
                                   updates_per_launch=updates_per_launch,
-                                  mesh=mesh, kernel_mode=kernel_mode)
+                                  mesh=mesh, kernel_mode=kernel_mode,
+                                  num_shards=num_shards)
         self.history = []
 
     # engine state, exposed under the historical names ------------------------

@@ -263,6 +263,9 @@ def test_async_kill_actor_reshards_without_hang():
     def on_update(u, md):
         if u >= 1 and not killed["done"]:
             eng.rollouts._procs[1].terminate()
+            # wait until it is gone: fragments already queued could
+            # otherwise finish the run before the death is observable
+            eng.rollouts._procs[1].join(timeout=30)
             killed["done"] = True
 
     try:

@@ -439,20 +439,17 @@ def test_audit_donation_consumed_passes():
 
 
 def test_audit_detects_f64_promotion():
-    from jax.experimental import enable_x64
-
     def widen(x):
         return x.astype(jnp.float64) * 2.0
 
-    with enable_x64():
+    with jax.enable_x64(True):
         res = audit_fn(widen, (jnp.ones((4,), jnp.float32),),
                        check_retrace=False)
     assert any(v.check == "f64-promotion" for v in res.violations)
 
 
 def test_audit_f64_input_is_allowed():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         res = audit_fn(lambda x: x * 2.0,
                        (jnp.ones((4,), jnp.float64),),
                        check_retrace=False)

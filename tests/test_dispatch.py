@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import compat, dispatch, ops, ref
+from repro.kernels import dispatch, ops, ref
 
 
 @pytest.fixture(autouse=True)
@@ -100,28 +100,6 @@ def test_scope_beats_env(monkeypatch):
             dispatch.CHUNKED
 
 
-# -- compat shim --------------------------------------------------------------
-
-def test_compiler_params_resolves_some_spelling():
-    cp = compat.compiler_params(
-        dimension_semantics=("parallel", "arbitrary"))
-    assert compat.HAS_PALLAS
-    assert cp is not None
-    assert tuple(cp.dimension_semantics) == ("parallel", "arbitrary")
-
-
-def test_compiler_params_drops_unknown_kwargs():
-    cp = compat.compiler_params(
-        dimension_semantics=("arbitrary",),
-        definitely_not_a_real_field_xyz=1)
-    assert cp is not None
-
-
-def test_jax_version_is_tuple_of_ints():
-    v = compat.jax_version()
-    assert len(v) >= 2 and all(isinstance(p, int) for p in v)
-
-
 # -- interpret-mode parity for every registered op ----------------------------
 
 def _parity_args(op):
@@ -143,15 +121,16 @@ def _parity_args(op):
         s = jnp.abs(r(3, (128,))) * 0.02
         return (x, wq, s), {}
     if op == "gae":
-        return (r(1, (4, 32)), r(2, (4, 32)),
-                jax.random.bernoulli(jax.random.fold_in(k0, 3), 0.2, (4, 32)),
+        return (r(1, (32, 4)), r(2, (32, 4)),
+                jax.random.bernoulli(jax.random.fold_in(k0, 3), 0.2, (32, 4)),
                 r(4, (4,)), 0.99, 0.95), dict(block_t=8)
     if op == "ssd":
-        x = r(1, (1, 32, 2, 8), 0.5)
-        dt = jax.nn.softplus(r(2, (1, 32, 2)))
+        # batch 2: grid rows past the head count must find their head's A
+        x = r(1, (2, 32, 2, 8), 0.5)
+        dt = jax.nn.softplus(r(2, (2, 32, 2)))
         A = -jnp.exp(r(3, (2,), 0.3))
-        B_ = r(4, (1, 32, 2, 8), 0.5)
-        C = r(5, (1, 32, 2, 8), 0.5)
+        B_ = r(4, (2, 32, 2, 8), 0.5)
+        C = r(5, (2, 32, 2, 8), 0.5)
         return (x, dt, A, B_, C), dict(chunk=8)
     if op == "pack":
         leaves = [jax.random.randint(jax.random.fold_in(k0, i), (4, n),
@@ -174,6 +153,27 @@ def test_interpret_matches_ref(op):
         got, want)
 
 
+@pytest.mark.parametrize("op", ["flash_attention", "ssd"])
+def test_interpret_grad_matches_ref(op):
+    """The ops the models train through differentiate on the Pallas
+    backends too (pallas_call itself has no autodiff rule)."""
+    args, kw = _parity_args(op)
+
+    def loss(mode, *a):
+        out = dispatch.call(op, *a, mode=mode, **kw)
+        return sum(jnp.sum(jnp.sin(o)) for o in jax.tree.leaves(out))
+
+    n = len(args)
+    want = jax.grad(lambda *a: loss("ref", *a), argnums=tuple(range(n)))(
+        *args)
+    got = jax.grad(lambda *a: loss("interpret", *a),
+                   argnums=tuple(range(n)))(*args)
+    jax.tree.map(
+        lambda g, w: np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=1e-4, rtol=1e-4),
+        got, want)
+
+
 # -- autotune -----------------------------------------------------------------
 
 def test_autotune_picks_winner_and_feeds_auto_dispatch():
@@ -192,13 +192,18 @@ def test_autotune_picks_winner_and_feeds_auto_dispatch():
     assert dispatch.resolve("gae", plat="cpu") == dispatch.REF
 
 
-def test_autotune_skips_broken_impls():
+def test_autotune_skips_broken_impls(monkeypatch):
+    """No impl is skipped: one that fails to lower or run raises out of
+    autotune instead of quietly losing to ``ref``, and nothing is cached."""
+    def broken(leaves):
+        raise RuntimeError("cannot lower")
+    monkeypatch.setitem(dispatch._REGISTRY["pack"], "broken",
+                        dispatch.Impl("pack", "broken", broken))
     args, kw = _parity_args("pack")
-    # compiled pallas can't run on CPU — autotune must skip it, not raise
-    results, best = dispatch.autotune(
-        "pack", *args, impls=(dispatch.REF, dispatch.PALLAS), iters=1, **kw)
-    assert best == dispatch.REF
-    dispatch.clear_autotune()
+    with pytest.raises(RuntimeError, match="cannot lower"):
+        dispatch.autotune("pack", *args, impls=(dispatch.REF, "broken"),
+                          iters=1, **kw)
+    assert dispatch.resolve("pack", plat="cpu") == dispatch.REF
 
 
 # -- ops-level round trip through the public wrappers -------------------------

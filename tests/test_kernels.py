@@ -72,9 +72,9 @@ def test_ssd_sweep(B, T, H, hd, ds, chunk):
 @pytest.mark.parametrize("done_p", [0.0, 0.1, 0.5])
 def test_gae_sweep(B, T, bt, done_p):
     k0 = jax.random.PRNGKey(B + T)
-    r = _rand(k0, (B, T))
-    v = _rand(jax.random.fold_in(k0, 1), (B, T))
-    d = jax.random.bernoulli(jax.random.fold_in(k0, 2), done_p, (B, T))
+    r = _rand(k0, (T, B))                   # time-major, as the learner
+    v = _rand(jax.random.fold_in(k0, 1), (T, B))
+    d = jax.random.bernoulli(jax.random.fold_in(k0, 2), done_p, (T, B))
     lv = _rand(jax.random.fold_in(k0, 3), (B,))
     want = ref.gae(r, v, d, lv, 0.99, 0.95)
     got = ops.gae(r, v, d, lv, 0.99, 0.95, mode="interpret", block_t=bt)
@@ -99,9 +99,9 @@ def test_gae_matches_python_reference():
             delta = r[b, t] + g * vn * nt - v[b, t]
             a = delta + g * lam * nt * a
             adv[b, t] = a
-    got = ref.gae(jnp.asarray(r), jnp.asarray(v), jnp.asarray(d),
-                  jnp.asarray(lv), g, lam)
-    np.testing.assert_allclose(np.asarray(got), adv, atol=1e-5, rtol=1e-5)
+    got = ref.gae(jnp.asarray(r.T), jnp.asarray(v.T), jnp.asarray(d.T),
+                  jnp.asarray(lv), g, lam)          # oracle is time-major
+    np.testing.assert_allclose(np.asarray(got).T, adv, atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("sizes", [(3,), (3, 7, 16), (1, 1, 1, 128)])

@@ -289,6 +289,9 @@ def test_healthz_flips_when_actor_killed():
     def on_update(u, md):
         if u >= 1 and not killed["done"]:
             eng.rollouts._procs[1].terminate()
+            # wait until it is gone: fragments already queued could
+            # otherwise finish the run before the death is observable
+            eng.rollouts._procs[1].join(timeout=30)
             killed["done"] = True
 
     srv = MetricsServer(port=0)

@@ -429,6 +429,9 @@ def test_actor_stat_slab_survives_killed_actor():
     def on_update(u, md):
         if u >= 1 and not killed["done"]:
             eng.rollouts._procs[1].terminate()
+            # wait until it is gone: fragments already queued could
+            # otherwise finish the run before the death is observable
+            eng.rollouts._procs[1].join(timeout=30)
             killed["done"] = True
 
     try:
