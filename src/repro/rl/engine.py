@@ -64,6 +64,7 @@ a host decision at the launch boundary.
 """
 from __future__ import annotations
 
+import re
 from collections import deque
 from typing import Callable, Optional
 
@@ -97,6 +98,74 @@ def pack_metrics(m: dict) -> jax.Array:
 
 def unpack_metrics(row) -> dict:
     return {k: float(v) for k, v in zip(METRIC_KEYS, row)}
+
+
+_SCOPE = re.compile(r"\bppo_[a-z_]+")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_FUSED = re.compile(r"\bcalls=(%[^ ,)]+)")
+_CALLEE = re.compile(r"\b(?:body|condition|to_apply|true_computation|"
+                     r"false_computation)=(%[^ ,]+)|"
+                     r"\bbranch_computations=\{([^}]*)\}")
+# control flow: its body's ops are trace events of their own
+_CONTROL = ("while", "conditional", "call")
+
+
+def _split_instruction(line: str):
+    """``%fusion.4 = f32[8]{0} fusion(%p), ...`` -> (``%fusion.4 =
+    f32[8]{0}``, ``fusion``): the instruction's text through its result
+    shape, which is how the profiler's op events begin, and its opcode.
+    The key stops before the opcode because an async op prints it two ways
+    (``slice-start`` from a fresh compile, ``async-start`` in the profiler
+    and from the compile cache)."""
+    name, rest = line.split(" = ", 1)
+    depth = 0
+    for i, ch in enumerate(rest):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        elif ch == " " and depth == 0:
+            break
+    return f"{name} = {rest[:i]}", rest[i + 1:].split("(", 1)[0]
+
+
+def hlo_op_scopes(hlo_text: str) -> dict:
+    """Each leaf instruction of an optimized HLO module's text mapped to
+    the innermost ``ppo_*`` named scope in its ``op_name`` metadata, keyed
+    by its text through the result shape (``_split_instruction``). An
+    instruction the compiler made without an ``op_name`` (a sunk clone, a
+    layout copy) takes the scope of the control-flow instruction whose
+    body holds it. Instructions of fusion bodies run inside their fusion
+    and control-flow instructions time their bodies' ops, so neither is a
+    key; instructions under no ``ppo_*`` scope are left out."""
+    fused = set(_FUSED.findall(hlo_text))
+    comps, comp = [], None          # [(computation, [instruction line])]
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            head = line.removeprefix("ENTRY ").split(" ", 1)[0]
+            comp = None
+            if head.startswith("%") and head not in fused:
+                comp = (head, [])
+                comps.append(comp)
+            continue
+        inst = line.strip().removeprefix("ROOT ")
+        if comp is not None and inst.startswith("%") and " = " in inst:
+            comp[1].append(inst)
+    # the text lists callees before callers, the entry computation last
+    inherited, out = {}, {}
+    for name, insts in reversed(comps):
+        for inst in insts:
+            m = _OP_NAME.search(inst)
+            scopes = _SCOPE.findall(m.group(1)) if m else ()
+            scope = (scopes[-1] if scopes else "") if m \
+                else inherited.get(name, "")
+            for one, branches in _CALLEE.findall(inst):
+                for callee in (one or branches).split(","):
+                    inherited.setdefault(callee.strip(), scope)
+            key, opcode = _split_instruction(inst)
+            if scope and opcode not in _CONTROL:
+                out[key] = scope
+    return out
 
 
 def _scan_launch(update, k: int, selfplay: bool = False):
@@ -348,6 +417,20 @@ class TrainEngine:
             self._launches[k] = jax.jit(fn, donate_argnums=(0, 1))
         return self._launches[k]
 
+    def op_scopes(self, k: int = 1) -> dict:
+        """The named scope (``ppo_rollout``, ``ppo_env_step``, ``ppo_learn``,
+        ``ppo_minibatch_rows``) of each leaf instruction of the compiled
+        k-update launch (``hlo_op_scopes``). A profiler op event belongs to
+        the scope of the key its text begins with. The launch is compiled
+        again through the persistent cache, so call it after a timed
+        window, never inside one."""
+        if self.backend not in ("jit", "shard_map"):
+            raise ValueError(f"op_scopes maps the fused launch of the jit "
+                             f"and shard_map tiers, not {self.backend!r}")
+        opp = () if self.selfplay is None else (self.ts.params,)
+        lowered = self._launch_for(k).lower(self.ts, self.rc, *opp, self.key)
+        return hlo_op_scopes(lowered.compile().as_text())
+
     def update_keys(self, key, k: int = None):
         """Per-update keys of one launch keyed by ``key`` — exposed so the
         fused-vs-sequential parity test can replay the exact schedule."""
@@ -506,21 +589,22 @@ class TrainEngine:
             u0, kk, ring = pending.popleft()
             with timer.fetch():
                 rows = np.asarray(jax.device_get(ring))
-            for i in range(kk):
-                md = unpack_metrics(rows[i])
-                timer.stamp(md, (u0 + i + 1) * spu)
-                history.append(md)
-                upd_ctr.inc()
+            with timer.drain():
+                for i in range(kk):
+                    md = unpack_metrics(rows[i])
+                    timer.stamp(md, (u0 + i + 1) * spu)
+                    history.append(md)
+                    upd_ctr.inc()
+                    if logger is not None:
+                        logger.log(md["env_steps"], md, flush=False)
+                    if on_update is not None:
+                        on_update(u0 + i, md)
+                    if (target_score is not None and solved is None
+                            and md["episodes"] > 0
+                            and md["score"] >= target_score):
+                        solved = md
                 if logger is not None:
-                    logger.log(md["env_steps"], md, flush=False)
-                if on_update is not None:
-                    on_update(u0 + i, md)
-                if (target_score is not None and solved is None
-                        and md["episodes"] > 0
-                        and md["score"] >= target_score):
-                    solved = md
-            if logger is not None:
-                logger.flush()
+                    logger.flush()
 
         u = self._resume_update
         while u < num_updates:
@@ -585,16 +669,18 @@ class TrainEngine:
                 md = {k: float(v) for k, v in
                       zip(METRIC_KEYS, jax.device_get([m[k] for k in
                                                        METRIC_KEYS]))}
-            timer.stamp(md, (uu + 1) * timer.spu)
-            history.append(md)
-            upd_ctr.inc()
-            if logger is not None:
-                logger.log(md["env_steps"], md)
-            if on_update is not None:
-                on_update(uu, md)
-            if (target_score is not None and st["solved"] is None
-                    and md["episodes"] > 0 and md["score"] >= target_score):
-                st["solved"] = md
+            with timer.drain():
+                timer.stamp(md, (uu + 1) * timer.spu)
+                history.append(md)
+                upd_ctr.inc()
+                if logger is not None:
+                    logger.log(md["env_steps"], md)
+                if on_update is not None:
+                    on_update(uu, md)
+                if (target_score is not None and st["solved"] is None
+                        and md["episodes"] > 0
+                        and md["score"] >= target_score):
+                    st["solved"] = md
         return drain_one
 
     def _run_pool(self, total_steps, *, target_score=None, on_update=None,

@@ -63,6 +63,15 @@ def _check_divisible(n, M, num_envs, num_minibatches, unroll_length, what):
             f"so each PPO minibatch has the same size")
 
 
+def _rows(x, idx, axis: int = 0):
+    """One minibatch's rows of ``x`` along ``axis``, under the named scope
+    that marks the learner's row reads in the compiled program. Each read
+    gets its own fresh scope where it stands: reading all rows first
+    reorders the learner loop's operands in the optimized program."""
+    with jax.named_scope("ppo_minibatch_rows"):
+        return x[idx] if axis == 0 else x[:, idx]
+
+
 def make_vtrace_adv(policy, dist, tcfg: TrainConfig,
                     rho_clip: float = 1.0, c_clip: float = 1.0):
     """V-trace advantage/target computation (IMPALA) for the async tier's
@@ -140,6 +149,12 @@ def make_ocean_learn(policy, tcfg: TrainConfig, dist,
     S = num_shards
 
     def learn(ts: TrainState, carry0, traj: Trajectory, last_value, key):
+        # a fresh scope per call: ``jax.named_scope`` objects are single-use
+        with jax.named_scope("ppo_learn"):
+            return learn_body(ts, carry0, traj, last_value, key)
+
+    def learn_body(ts: TrainState, carry0, traj: Trajectory, last_value,
+                   key):
         T, B = traj.rewards.shape                       # local shapes
         B_global = B * (S if axis_name is not None else 1)
 
@@ -158,19 +173,20 @@ def make_ocean_learn(policy, tcfg: TrainConfig, dist,
                              f"envs per data shard ({S} shards)")
 
             def loss_fn(params, idx):
-                obs = traj.obs[:, idx]
+                obs = _rows(traj.obs, idx, 1)
                 logits, newv, _ = policy.seq(
                     params, obs,
-                    jax.tree.map(lambda c: c[idx], carry0)
+                    jax.tree.map(lambda c: _rows(c, idx), carry0)
                     if carry0 is not None else None,
-                    traj.resets[:, idx])
-                newlogp = dist.log_prob(logits, traj.actions[:, idx])
+                    _rows(traj.resets, idx, 1))
+                newlogp = dist.log_prob(logits, _rows(traj.actions, idx, 1))
                 ent = dist.entropy(logits)
-                a = ppo.normalize_adv(adv[:, idx], tcfg.norm_adv, axis_name)
-                pg, kl, cf = ppo.ppo_terms(newlogp, traj.logprobs[:, idx],
-                                           a, tcfg)
-                vl = ppo.value_loss(newv, traj.values[:, idx],
-                                    returns[:, idx], tcfg)
+                a = ppo.normalize_adv(_rows(adv, idx, 1), tcfg.norm_adv,
+                                      axis_name)
+                pg, kl, cf = ppo.ppo_terms(
+                    newlogp, _rows(traj.logprobs, idx, 1), a, tcfg)
+                vl = ppo.value_loss(newv, _rows(traj.values, idx, 1),
+                                    _rows(returns, idx, 1), tcfg)
                 loss = pg - tcfg.ent_coef * jnp.mean(ent) + tcfg.vf_coef * vl
                 return loss, ppo.PPOStats(pg, vl, jnp.mean(ent), kl, cf)
 
@@ -188,12 +204,16 @@ def make_ocean_learn(policy, tcfg: TrainConfig, dist,
                              f"samples per data shard ({S} shards)")
 
             def loss_fn(params, idx):
-                logits, newv, _ = policy.step(params, flat.obs[idx], None)
-                newlogp = dist.log_prob(logits, flat.actions[idx])
+                logits, newv, _ = policy.step(params, _rows(flat.obs, idx),
+                                              None)
+                newlogp = dist.log_prob(logits, _rows(flat.actions, idx))
                 ent = dist.entropy(logits)
-                a = ppo.normalize_adv(flat_adv[idx], tcfg.norm_adv, axis_name)
-                pg, kl, cf = ppo.ppo_terms(newlogp, flat.logprobs[idx], a, tcfg)
-                vl = ppo.value_loss(newv, flat.values[idx], flat_ret[idx], tcfg)
+                a = ppo.normalize_adv(_rows(flat_adv, idx), tcfg.norm_adv,
+                                      axis_name)
+                pg, kl, cf = ppo.ppo_terms(newlogp, _rows(flat.logprobs, idx),
+                                           a, tcfg)
+                vl = ppo.value_loss(newv, _rows(flat.values, idx),
+                                    _rows(flat_ret, idx), tcfg)
                 loss = pg - tcfg.ent_coef * jnp.mean(ent) + tcfg.vf_coef * vl
                 return loss, ppo.PPOStats(pg, vl, jnp.mean(ent), kl, cf)
 

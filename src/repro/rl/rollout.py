@@ -49,6 +49,9 @@ def rollout(policy, params, step_fn, carry: RolloutCarry, key,
     exactly the keys the single-device run draws for those envs.
     """
 
+    # named scopes mark the device ops of each phase in the compiled
+    # program's metadata (``TrainEngine.op_scopes``); one fresh context per
+    # ``with``, as ``jax.named_scope`` objects are single-use
     def one(c: RolloutCarry, k):
         k_act, k_env = jax.random.split(k)
         logits, value, pc = policy.step(params, c.obs, c.policy_carry,
@@ -56,8 +59,9 @@ def rollout(policy, params, step_fn, carry: RolloutCarry, key,
         if keyed is None:
             action = dist.sample(k_act, logits)
             logp = dist.log_prob(logits, action)
-            env_state, obs, rew, done, info = step_fn(c.env_state, action,
-                                                      k_env)
+            with jax.named_scope("ppo_env_step"):
+                env_state, obs, rew, done, info = step_fn(c.env_state,
+                                                          action, k_env)
         else:
             num_envs, off = keyed
             batch = logits.shape[0]
@@ -69,16 +73,18 @@ def rollout(policy, params, step_fn, carry: RolloutCarry, key,
             logp = dist.log_prob(logits, action)
             env_keys = jax.vmap(lambda i: jax.random.fold_in(k_env, i))(
                 off + jnp.arange(num_envs))
-            env_state, obs, rew, done, info = step_fn(c.env_state, action,
-                                                      env_keys)
+            with jax.named_scope("ppo_env_step"):
+                env_state, obs, rew, done, info = step_fn(c.env_state,
+                                                          action, env_keys)
         out = Trajectory(c.obs, action, logp, value, rew, done,
                          c.done_prev, info)
         return RolloutCarry(env_state, obs, pc, done), out
 
-    keys = jax.random.split(key, unroll)
-    carry, traj = jax.lax.scan(one, carry, keys)
+    with jax.named_scope("ppo_rollout"):
+        keys = jax.random.split(key, unroll)
+        carry, traj = jax.lax.scan(one, carry, keys)
 
-    # bootstrap value for GAE
-    _, last_value, _ = policy.step(params, carry.obs, carry.policy_carry,
-                                   reset=carry.done_prev)
+        # bootstrap value for GAE
+        _, last_value, _ = policy.step(params, carry.obs, carry.policy_carry,
+                                       reset=carry.done_prev)
     return carry, traj, last_value

@@ -14,6 +14,16 @@ Design constraints, in order:
    fetches, shared-memory waits. They must never appear inside jitted
    functions (they would run once at trace time and lie forever); the
    ``TELEMETRY-IN-JIT`` rule in ``repro.analysis`` enforces this statically.
+4. **On the device trace's clock.** A tracer enabled in a process that has
+   already imported jax also opens a ``jax.profiler.TraceAnnotation`` of
+   each span's name, so under ``jax.profiler.start_trace`` the spans land
+   on the profiler's host plane beside the device ops. This module never
+   imports jax itself.
+
+Python's garbage collector is traced too while tracing is on: each
+collection is a ``python.gc`` span and counts into the registry counter
+``python.gc_collections{generation}`` (``gc.callbacks``, registered by
+``enable`` and removed by ``disable``).
 
 Nesting is tracked per-thread/task via a ``contextvars.ContextVar`` depth
 counter so the Chrome trace export reconstructs the flame graph. Export
@@ -25,14 +35,18 @@ this module before jax exists in their interpreter.
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
+import sys
 import threading
 import time
 import uuid
 from collections import deque
 from contextvars import ContextVar
 from typing import List, NamedTuple, Optional
+
+from repro.telemetry.registry import registry
 
 __all__ = [
     "Tracer", "SpanRecord", "span", "CachedSpan", "enable", "disable",
@@ -93,25 +107,38 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` where this process has imported jax,
+    else None. Looks in ``sys.modules`` only: importing jax here would
+    break the jax-free spawn workers."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    return getattr(profiler, "TraceAnnotation", None)
+
+
 class _Span:
     """One live span (enabled path). Records itself into the tracer ring on
     exit; exceptions propagate (the span still records its duration)."""
-    __slots__ = ("_ring", "name", "_t0", "_tok", "_depth", "_parent")
+    __slots__ = ("_ring", "name", "_annotation", "_ann", "_t0", "_tok",
+                 "_depth", "_parent")
 
-    def __init__(self, ring: deque, name: str):
+    def __init__(self, ring: deque, name: str, annotation=None):
         self._ring = ring
         self.name = name
+        self._annotation = annotation
 
     def __enter__(self):
         depth, _parent = _STACK.get((0, ""))   # ContextVar read, never blocks
         self._depth = depth
         self._parent = _parent
         self._tok = _STACK.set((depth + 1, self.name))
+        self._ann = _open(self._annotation, self.name)
         self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, et, ev, tb):
         dur = time.monotonic_ns() - self._t0
+        _close(self._ann, et, ev, tb)
         _STACK.reset(self._tok)
         self._ring.append(SpanRecord(
             self.name, self._t0, dur, os.getpid(),
@@ -119,17 +146,32 @@ class _Span:
         return False
 
 
+def _open(annotation, name: str):
+    if annotation is None:
+        return None
+    ann = annotation(name)
+    ann.__enter__()
+    return ann
+
+
+def _close(ann, et=None, ev=None, tb=None) -> None:
+    if ann is not None:
+        ann.__exit__(et, ev, tb)
+
+
 class CachedSpan:
     """A reusable named span for non-reentrant hot call sites.
 
     ``span(name)`` allocates one ``_Span`` per use on the enabled path;
     a ``CachedSpan`` held by the call site (e.g. ``TierTimer``'s launch /
-    fetch contexts) is allocation-free in BOTH modes: the tracer is
-    re-read on every ``__enter__`` so mid-run enable/disable still works.
-    Not safe for the same instance to be entered concurrently from two
-    threads or re-entered recursively — one instance per call site.
+    fetch contexts) allocates nothing when disabled, and when enabled only
+    the profiler annotation where the tracer mirrors spans (module
+    docstring, point 4): the tracer is re-read on every ``__enter__`` so
+    mid-run enable/disable still works. Not safe for the same instance to
+    be entered concurrently from two threads or re-entered recursively —
+    one instance per call site.
     """
-    __slots__ = ("name", "_ring", "_t0", "_tok", "_depth", "_parent")
+    __slots__ = ("name", "_ring", "_ann", "_t0", "_tok", "_depth", "_parent")
 
     def __init__(self, name: str):
         self.name = name
@@ -145,6 +187,7 @@ class CachedSpan:
         self._depth = depth
         self._parent = parent
         self._tok = _STACK.set((depth + 1, self.name))
+        self._ann = _open(t.annotation, self.name)
         self._t0 = time.monotonic_ns()
         return self
 
@@ -153,6 +196,8 @@ class CachedSpan:
         if ring is None:
             return False
         dur = time.monotonic_ns() - self._t0
+        _close(self._ann, et, ev, tb)
+        self._ann = None
         _STACK.reset(self._tok)
         self._ring = None
         ring.append(SpanRecord(
@@ -182,8 +227,17 @@ class Tracer:
         self.trace_id = trace_id or uuid.uuid4().hex[:16]
         self.role = role
         self.clock_offset_ns = clock_offset_ns()
+        self.annotation = _profiler_annotation()
         self._ring: deque = deque(maxlen=self.capacity)
         self._io_lock = threading.Lock()
+        self._gc_t0 = None
+        self._gc_ann = None
+        # handles made here: a collection can start while the registry's
+        # lock is held (an allocation under it), so the callback must not
+        # create an instrument
+        self._gc_counts = [registry().counter("python.gc_collections",
+                                              generation=g)
+                           for g in range(3)]
         if run_dir:
             os.makedirs(run_dir, exist_ok=True)
             self._write_meta()
@@ -200,7 +254,28 @@ class Tracer:
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str) -> _Span:
-        return _Span(self._ring, name)
+        return _Span(self._ring, name, self.annotation)
+
+    def gc_event(self, phase: str, info: dict) -> None:
+        """A ``gc.callbacks`` event: ``start`` opens the ``python.gc`` span,
+        ``stop`` records it and counts the collection by generation. The
+        collector runs one collection at a time, so one open span will do.
+        """
+        if phase == "start":
+            self._gc_ann = _open(self.annotation, GC_SPAN)
+            self._gc_t0 = time.monotonic_ns()
+            return
+        t0 = self._gc_t0
+        if t0 is None:               # enabled during a collection
+            return
+        dur = time.monotonic_ns() - t0
+        _close(self._gc_ann)
+        self._gc_t0 = self._gc_ann = None
+        depth, parent = _STACK.get((0, ""))
+        self._ring.append(SpanRecord(
+            GC_SPAN, t0, dur, os.getpid(),
+            threading.get_ident() & 0xFFFFFFFF, depth, parent))
+        self._gc_counts[info["generation"]].inc()
 
     def records(self) -> List[SpanRecord]:
         """Snapshot of the ring without draining it."""
@@ -244,6 +319,7 @@ class Tracer:
 
 # -- module-level switch ---------------------------------------------------
 _TRACER: Optional[Tracer] = None
+GC_SPAN = "python.gc"
 
 
 def span(name: str):
@@ -252,7 +328,13 @@ def span(name: str):
     t = _TRACER
     if t is None:
         return _NULL_SPAN
-    return _Span(t._ring, name)
+    return _Span(t._ring, name, t.annotation)
+
+
+def _gc_callback(phase: str, info: dict) -> None:
+    t = _TRACER
+    if t is not None:
+        t.gc_event(phase, info)
 
 
 def enable(run_dir: Optional[str] = None, capacity: int = 65536, *,
@@ -267,12 +349,16 @@ def enable(run_dir: Optional[str] = None, capacity: int = 65536, *,
         return _TRACER
     _TRACER = Tracer(run_dir=run_dir, capacity=capacity,
                      file_name=file_name, trace_id=trace_id, role=role)
+    if _gc_callback not in gc.callbacks:
+        gc.callbacks.append(_gc_callback)
     return _TRACER
 
 
 def disable() -> None:
     """Turn tracing off (flushing any pending spans first)."""
     global _TRACER
+    if _gc_callback in gc.callbacks:
+        gc.callbacks.remove(_gc_callback)
     if _TRACER is not None:
         try:
             _TRACER.flush()

@@ -12,10 +12,12 @@ tier's history records carry the *same* keys with the *same* semantics:
 
 ``launch()`` / ``fetch()`` return context managers that both time the block
 and open the matching span (``engine.launch`` / ``engine.fetch``), so the
-Chrome trace and the history records agree by construction. Both contexts
-are pre-built once per TierTimer on a ``CachedSpan`` — the per-launch hot
-loop allocates nothing and the tracer enabled-check happens exactly once
-per block entry, whether tracing is on or off.
+Chrome trace and the history records agree by construction. ``drain()`` is
+the span alone (``engine.drain``): the host work on a fetched update's
+metrics (stamp, history, logger, ``on_update``, target check). All three
+contexts are pre-built once per TierTimer on a ``CachedSpan`` — the
+per-launch hot loop allocates nothing and the tracer enabled-check happens
+exactly once per block entry, whether tracing is on or off.
 
 jax-free (stdlib only).
 """
@@ -63,12 +65,16 @@ class TierTimer:
         self.fetch_ms = 0.0
         self._launch = _Timed(self, "launch_ms", "engine.launch")
         self._fetch = _Timed(self, "fetch_ms", "engine.fetch")
+        self._drain = CachedSpan("engine.drain")
 
     def launch(self) -> _Timed:
         return self._launch
 
     def fetch(self) -> _Timed:
         return self._fetch
+
+    def drain(self) -> CachedSpan:
+        return self._drain
 
     def elapsed(self) -> float:
         return time.perf_counter() - self.t0

@@ -194,6 +194,33 @@ def test_metrics_ring_pack_unpack_roundtrip():
     assert unpack_metrics(np.asarray(row)) == m
 
 
+@pytest.mark.parametrize("recurrent", [False, True])
+def test_op_scopes_maps_leaf_instructions_to_each_scope(recurrent):
+    from repro.envs.ocean import Bandit
+    eng = _build(Bandit(), recurrent=recurrent)
+    scopes = eng.op_scopes()
+    assert set(scopes.values()) == {"ppo_rollout", "ppo_env_step",
+                                    "ppo_learn", "ppo_minibatch_rows"}
+    text = eng._launch_for(1).lower(eng.ts, eng.rc, eng.key).compile() \
+        .as_text()
+    whiles = {line.strip().removeprefix("ROOT ").split(" = ")[0]
+              for line in text.splitlines() if " while(" in line}
+    assert whiles and not whiles & {k.split(" = ")[0] for k in scopes}
+
+
+def test_fused_run_spans_each_drain():
+    from repro import telemetry
+    from repro.envs.ocean import Bandit
+    eng = _build(Bandit())
+    telemetry.enable()
+    try:
+        eng.run(3 * eng.steps_per_update)
+        names = [r.name for r in telemetry.get_tracer().records()]
+    finally:
+        telemetry.disable()
+    assert names.count("engine.launch") == names.count("engine.drain") == 3
+
+
 SHARD_PARITY = r"""
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")

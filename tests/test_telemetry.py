@@ -28,6 +28,12 @@ from repro.utils import metrics as ml
 RECV_T = 30.0
 
 
+def _own(recs):
+    """The records a test made: a collection under tracing adds a
+    ``python.gc`` span to the same ring at any allocation."""
+    return [r for r in recs if r.name != tspans.GC_SPAN]
+
+
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
     """Span tracing and the default registry are process-global switches —
@@ -70,6 +76,54 @@ def test_disabled_span_allocates_nothing():
     assert sum(s.size_diff for s in stats) == 0
 
 
+def test_spans_land_on_the_profiler_host_plane(tmp_path):
+    """With jax imported, a span (either kind) also opens a profiler
+    annotation of its name, on the clock of the device trace."""
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+    telemetry.enable()
+    cached = tspans.CachedSpan("test.cached_span")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.span("test.span"):
+            with cached:
+                jnp.ones(3).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    prof = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    names = {ev.name for plane in prof.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"test.span", "test.cached_span"} <= names
+    assert [r.name for r in _own(telemetry.get_tracer().records())] == [
+        "test.cached_span", "test.span"]
+
+
+def test_gc_collections_are_spans_and_counted():
+    import gc
+    telemetry.enable()
+    gc.collect()
+    recs = [r for r in telemetry.get_tracer().records()
+            if r.name == "python.gc"]
+    count = registry().counter("python.gc_collections", generation=2)
+    assert recs and recs[-1].dur_ns > 0 and count.value >= 1
+    telemetry.disable()
+    assert tspans._gc_callback not in gc.callbacks
+    seen = count.value
+    gc.collect()
+    assert count.value == seen
+
+
+def test_compile_counters_count_a_fresh_jit():
+    import jax.numpy as jnp
+    from repro.utils.compile_cache import count_compiles
+    count_compiles()
+    jax.jit(lambda x: x * 3.0 + 1.0)(jnp.arange(7.0)).block_until_ready()
+    counters = registry().snapshot()["counters"]
+    assert counters["jax.compiles"] >= 1
+    assert counters["jax.compile_s"] > 0
+
+
 def test_disabled_span_tight_loop_bound():
     n = 200_000
     t0 = time.perf_counter()
@@ -103,7 +157,7 @@ def test_span_ring_is_bounded():
             pass
     recs = telemetry.get_tracer().records()
     assert len(recs) == 16
-    assert recs[-1].name == "s99"         # newest survive
+    assert _own(recs)[-1].name == "s99"   # newest survive
 
 
 def test_span_ring_is_thread_safe():
@@ -119,7 +173,7 @@ def test_span_ring_is_thread_safe():
         t.start()
     for t in threads:
         t.join(timeout=30)
-    assert len(telemetry.get_tracer().drain()) == 8 * 500
+    assert len(_own(telemetry.get_tracer().drain())) == 8 * 500
 
 
 def test_reenable_same_args_keeps_tracer():
@@ -127,7 +181,7 @@ def test_reenable_same_args_keeps_tracer():
     with telemetry.span("kept"):
         pass
     t2 = telemetry.enable()
-    assert t1 is t2 and len(t2.records()) == 1
+    assert t1 is t2 and len(_own(t2.records())) == 1
 
 
 def test_chrome_trace_structure():
@@ -135,7 +189,7 @@ def test_chrome_trace_structure():
     with telemetry.span("a"):
         with telemetry.span("b"):
             pass
-    trace = tspans.chrome_trace(telemetry.get_tracer().records())
+    trace = tspans.chrome_trace(_own(telemetry.get_tracer().records()))
     evs = trace["traceEvents"]
     assert len(evs) == 2
     for ev in evs:
@@ -319,8 +373,10 @@ def test_tier_timer_opens_spans_when_enabled():
         pass
     with timer.fetch():
         pass
-    names = [r.name for r in telemetry.get_tracer().records()]
-    assert names == ["engine.launch", "engine.fetch"]
+    with timer.drain():
+        pass
+    names = [r.name for r in _own(telemetry.get_tracer().records())]
+    assert names == ["engine.launch", "engine.fetch", "engine.drain"]
 
 
 @pytest.mark.timeout(300)
