@@ -17,6 +17,7 @@ registry: ``kernel_mode``/``gae_mode`` of ``None`` means the registry picks
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -29,6 +30,7 @@ from repro.optim import adamw, schedule
 from repro.rl import distributions as D
 from repro.rl import ppo
 from repro.rl.rollout import rollout, RolloutCarry, Trajectory
+from repro.telemetry import registry as tel_registry
 
 
 class TrainState(NamedTuple):
@@ -65,11 +67,64 @@ def _check_divisible(n, M, num_envs, num_minibatches, unroll_length, what):
 
 def _rows(x, idx, axis: int = 0):
     """One minibatch's rows of ``x`` along ``axis``, under the named scope
-    that marks the learner's row reads in the compiled program. Each read
-    gets its own fresh scope where it stands: reading all rows first
-    reorders the learner loop's operands in the optimized program."""
+    that marks the learner's row reads in the compiled program. The
+    recurrent branch reads every field so (along the env axis), and the
+    packed read (``RowSlab``) each field that is not 32 bits wide."""
     with jax.named_scope("ppo_minibatch_rows"):
         return x[idx] if axis == 0 else x[:, idx]
+
+
+class RowSlab:
+    """The per-sample fields of a flat batch, packed for one row read per
+    minibatch.
+
+    Every leaf whose dtype is 32 bits wide is reshaped to ``(N, k)``,
+    bit-cast to ``uint32`` and concatenated into one ``(N, sum k)`` slab;
+    ``read(idx)`` gathers the minibatch's rows of the slab once and
+    bit-casts each field's columns back. The read is pure integer data
+    movement, so it is bit-identical to reading each field as ``x[idx]``:
+    nothing on the way rounds (no float conversion, matmul or one-hot
+    select), which the TPU would do at the default precision. On the TPU
+    the cost of a gather is per index, not per byte, so one row read of the
+    slab replaces one read per field. Leaves of other widths (bool, uint8,
+    bfloat16) are read on their own, each with its own gather."""
+
+    def __init__(self, fields):
+        self._leaves, self._treedef = jax.tree.flatten(fields)
+        self._offsets, cols, self.columns = [], [], 0
+        with jax.named_scope("ppo_minibatch_rows"):
+            for x in self._leaves:
+                if jnp.dtype(x.dtype).itemsize != 4:
+                    self._offsets.append(None)
+                    continue
+                k = math.prod(x.shape[1:])
+                cols.append(jax.lax.bitcast_convert_type(
+                    x.reshape(x.shape[0], k), jnp.uint32))
+                self._offsets.append(self.columns)
+                self.columns += k
+            self.slab = jnp.concatenate(cols, axis=1)
+        self.separate = self._offsets.count(None)
+
+    def read(self, idx):
+        """The rows ``idx`` of every field, in the tree of ``fields``."""
+        out = []
+        with jax.named_scope("ppo_minibatch_rows"):
+            rows = self.slab[idx]
+            for x, off in zip(self._leaves, self._offsets):
+                if off is None:
+                    out.append(x[idx])
+                    continue
+                k = math.prod(x.shape[1:])
+                col = jax.lax.slice_in_dim(rows, off, off + k, axis=1)
+                out.append(jax.lax.bitcast_convert_type(col, x.dtype)
+                           .reshape(idx.shape + x.shape[1:]))
+            # each field leaves as a buffer of its own, as a per-field read
+            # leaves it: the loss then reduces over it (the advantages' mean
+            # and deviation) in the same order, and the packed read changes
+            # no rounding after it. Reduced straight out of the slab's rows,
+            # the sums take another order on the TPU.
+            out = jax.lax.optimization_barrier(out)
+        return self._treedef.unflatten(out)
 
 
 def make_vtrace_adv(policy, dist, tcfg: TrainConfig,
@@ -192,28 +247,27 @@ def make_ocean_learn(policy, tcfg: TrainConfig, dist,
 
             n_loc = n_block
             to_global = lambda p, s: s * n_block + p
+            packed, separate = 0, len(jax.tree.leaves(
+                (traj.obs, carry0, traj.resets, traj.actions, adv,
+                 traj.logprobs, traj.values, returns)))
         else:
-            flat = jax.tree.map(
-                lambda x: x.reshape((T * B,) + x.shape[2:]),
-                Trajectory(traj.obs, traj.actions, traj.logprobs, traj.values,
-                           traj.rewards, traj.dones, traj.resets, {}))
-            flat_adv = adv.reshape(-1)
-            flat_ret = returns.reshape(-1)
             n_block = B if axis_name is not None else B // S
             _check_divisible(T * n_block, M, B_global, M, T,
                              f"samples per data shard ({S} shards)")
+            rows = RowSlab(jax.tree.map(
+                lambda x: x.reshape((T * B,) + x.shape[2:]),
+                (traj.obs, traj.actions, traj.logprobs, traj.values, adv,
+                 returns)))
+            packed, separate = rows.columns, rows.separate
 
             def loss_fn(params, idx):
-                logits, newv, _ = policy.step(params, _rows(flat.obs, idx),
-                                              None)
-                newlogp = dist.log_prob(logits, _rows(flat.actions, idx))
+                obs, actions, logprobs, values, mb_adv, mb_ret = rows.read(idx)
+                logits, newv, _ = policy.step(params, obs, None)
+                newlogp = dist.log_prob(logits, actions)
                 ent = dist.entropy(logits)
-                a = ppo.normalize_adv(_rows(flat_adv, idx), tcfg.norm_adv,
-                                      axis_name)
-                pg, kl, cf = ppo.ppo_terms(newlogp, _rows(flat.logprobs, idx),
-                                           a, tcfg)
-                vl = ppo.value_loss(newv, _rows(flat.values, idx),
-                                    _rows(flat_ret, idx), tcfg)
+                a = ppo.normalize_adv(mb_adv, tcfg.norm_adv, axis_name)
+                pg, kl, cf = ppo.ppo_terms(newlogp, logprobs, a, tcfg)
+                vl = ppo.value_loss(newv, values, mb_ret, tcfg)
                 loss = pg - tcfg.ent_coef * jnp.mean(ent) + tcfg.vf_coef * vl
                 return loss, ppo.PPOStats(pg, vl, jnp.mean(ent), kl, cf)
 
@@ -221,6 +275,10 @@ def make_ocean_learn(policy, tcfg: TrainConfig, dist,
             # block-local flat index (t * n_block + e) → global (t * B + env)
             to_global = lambda p, s: ((p // n_block) * B + s * n_block
                                       + p % n_block)
+
+        reg = tel_registry()
+        reg.gauge("learner.rows_packed_columns").set(packed)
+        reg.gauge("learner.rows_separate_fields").set(separate)
 
         def mb_step(ts: TrainState, idx):
             (loss, stats), grads = jax.value_and_grad(
