@@ -16,6 +16,9 @@ from pathlib import Path
 from bench import faults
 
 READ_STEPS = 3
+# the overrides of the traffic file and of config.json that run this
+# configuration at a test size on the CPU
+TEST_SIZE = {"traffic": {"num_envs": 16}, "config": {}}
 HERE = Path(__file__).resolve().parent
 
 

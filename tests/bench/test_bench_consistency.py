@@ -129,6 +129,74 @@ def test_a_new_cell_traffic_and_metric_are_found_by_name(tmp_path):
     assert out["metrics"]["agent_steps_per_s"]["value"] > 0
 
 
+def test_every_configuration_declares_a_test_size():
+    """Each configuration's driver declares the overrides that run it on
+    the CPU, and every key it overrides exists in the configuration or in
+    each of its cells' traffic: a misspelt key fails here instead of
+    running a model at full width."""
+    for c in BM["configs"]:
+        drv = harness.driver(c["name"])
+        assert hasattr(drv, "TEST_SIZE"), c["name"]
+        size = drv.TEST_SIZE
+        assert set(size) == {"traffic", "config"}, c["name"]
+        assert set(size["config"]) <= set(harness.config(c["name"])), \
+            c["name"]
+        for w in BM["workloads"]:
+            if w["config"] == c["name"]:
+                assert set(size["traffic"]) <= set(
+                    harness.traffic(w["traffic"])), w["name"]
+
+
+def _files(root: Path) -> dict:
+    return {p.relative_to(root).as_posix(): p.read_bytes()
+            for p in root.rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+def test_a_new_configuration_is_found_by_name(tmp_path):
+    """A copy of the benchmark gains a configuration (Ocean Squared under
+    another name), its traffic file and a cell as new files and entries
+    alone; the unchanged harness runs the cell at the new driver's test
+    size, the control tests take it, and no file that was there changes
+    but ``BENCHMARK.json``."""
+    from tests.bench.test_bench_control import at_test_size, outcomes_of
+    bench = tmp_path / "bench"
+    shutil.copytree(ROOT / "bench", bench,
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(BM))
+    before = _files(tmp_path)
+
+    name, cell = "ocean-squared-copy", "copy.jit.e4096"
+    shutil.copytree(bench / "configs" / "ocean-squared",
+                    bench / "configs" / name)
+    cfg = dict(harness.config("ocean-squared", bench), name=name)
+    (bench / "configs" / name / "config.json").write_text(json.dumps(cfg))
+    (bench / "traffic" / f"{cell}.json").write_text(
+        json.dumps(harness.traffic("squared.jit.e4096", bench)))
+    bm = json.loads(json.dumps(BM))
+    entry = next(c for c in bm["configs"] if c["name"] == "ocean-squared")
+    bm["configs"].append(dict(entry, name=name,
+                              file=f"bench/configs/{name}/config.json"))
+    bm["workloads"].append({"name": cell, "config": name, "traffic": cell,
+                            "chips": 1, "why": "a test cell"})
+    for m in bm["end_to_end"]:
+        if m["name"] == "agent_steps_per_s":
+            m["workloads"].append(cell)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bm))
+
+    with at_test_size(name, bench) as tfc:
+        out = harness.run(cell, 5, 0.5, False, require_tpu=False,
+                          traffic_override=tfc, bench=bench, root=tmp_path)
+    assert out["correct"], out["checks"]
+    assert set(out["metrics"]) == {"agent_steps_per_s", "setup_s"}
+    res = outcomes_of(name, harness.traffic(cell, bench), 1, bench,
+                      faults=["state_unchanged"], control=False)
+    assert res == {"sound": True, "state_unchanged": False}
+    after = _files(tmp_path)
+    assert [f for f in before if f != "BENCHMARK.json"
+            and after.get(f) != before[f]] == []
+
+
 def test_run_exits_nonzero_without_a_tpu():
     import os
     import subprocess

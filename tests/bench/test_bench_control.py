@@ -12,12 +12,16 @@ program's place, must fail at least one compared number.
 
 Cases: every cell of ``BENCHMARK.json`` under its own limits (set on the
 chip), and the shard_map tier of the Ocean cell on four virtual CPU
-devices in a child process, under the jit cell's limits."""
+devices in a child process, under the jit cell's limits. Each
+configuration runs at the test size its driver declares in ``TEST_SIZE``,
+so a new configuration needs no edit here."""
+import contextlib
 import json
 import os
 import subprocess
 import sys
 from pathlib import Path
+from unittest import mock
 
 import pytest
 
@@ -28,37 +32,52 @@ from bench import compare, harness  # noqa: E402
 
 SEED = 2**31 + 99
 BM = harness.benchmark()
-# per configuration: traffic and configuration overrides of the test size
-SIZES = {"ocean-squared": ({"num_envs": 16}, {})}
 # case -> (configuration, traffic, chips)
 CASES = {w["name"]: (w["config"], harness.traffic(w["traffic"]), w["chips"])
          for w in BM["workloads"]}
+_JIT = harness.workload(BM, "squared.jit.e4096")
 CASES["squared.shard_map.test"] = (
-    "ocean-squared", dict(harness.traffic("squared.jit.e4096"),
-                          tier="shard_map"), 4)
+    _JIT["config"], dict(harness.traffic(_JIT["traffic"]), tier="shard_map"),
+    4)
 
 
-def _faults(case):
-    name, _, chips = CASES[case]
-    return [f for f in harness.driver(name).FAULTS
+def _faults(config, chips, bench=harness.BENCH):
+    return [f for f in harness.driver(config, bench).FAULTS
             if f != "no_exchange" or chips > 1]
 
 
-def outcomes(case: str) -> dict:
-    """``correct`` of the sound run and of each fault, and whether the
-    control fails a compared number: ``{case: bool}``."""
-    name, traffic, chips = CASES[case]
-    tfc, cfg = SIZES[name]
-    traffic = dict(traffic, **tfc)
-    config = dict(harness.config(name), **cfg)
-    drv = harness.driver(name)
+@contextlib.contextmanager
+def at_test_size(config, bench=harness.BENCH):
+    """For ``harness.run`` of a cell of ``config`` at the driver's test
+    size: the configuration's overrides lie over ``harness.config``
+    meanwhile, and the traffic's are yielded for ``traffic_override``."""
+    size = harness.driver(config, bench).TEST_SIZE
+    real = harness.config
+
+    def config_at_size(name, bench=harness.BENCH):
+        cfg = real(name, bench)
+        return dict(cfg, **size["config"]) if name == config else cfg
+    with mock.patch.object(harness, "config", config_at_size):
+        yield size["traffic"]
+
+
+def outcomes_of(config, traffic, chips, bench=harness.BENCH, faults=None,
+                control=True) -> dict:
+    """``correct`` of the sound run and of each fault (those given, else
+    every fault of the driver that ``chips`` can have), and whether the
+    control fails a compared number: ``{case: bool}``. ``config`` names the
+    configuration under ``bench``; it and ``traffic`` run at the driver's
+    test size."""
+    drv = harness.driver(config, bench)
+    cfg = dict(harness.config(config, bench), **drv.TEST_SIZE["config"])
+    traffic = dict(traffic, **drv.TEST_SIZE["traffic"])
     limits = traffic["limits"]
 
     def fails(nums):
         return any(nums[k] > v for k, v in limits.items())
 
     def cell():
-        return drv.Cell(config, traffic, SEED, chips)
+        return drv.Cell(cfg, traffic, SEED, chips)
 
     ref = cell().reference_side()
 
@@ -73,11 +92,17 @@ def outcomes(case: str) -> dict:
         return not fails(c.numbers(ref))
 
     out = {"sound": correct()}
-    for f in _faults(case):
+    for f in _faults(config, chips, bench) if faults is None else faults:
         out[f] = correct(f)
-    out["control_fails"] = fails(compare.training_numbers(
-        cell().reference_side(control=True), ref))
+    if control:
+        out["control_fails"] = fails(compare.training_numbers(
+            cell().reference_side(control=True), ref))
     return out
+
+
+def outcomes(case: str) -> dict:
+    """``outcomes_of`` the case's configuration, traffic and chips."""
+    return outcomes_of(*CASES[case])
 
 
 @pytest.fixture(scope="module")
@@ -111,8 +136,9 @@ def test_sound_run_is_correct(results, case):
     assert results(case)["sound"]
 
 
-@pytest.mark.parametrize("case,fault", [(c, f) for c in CASES
-                                        for f in _faults(c)])
+@pytest.mark.parametrize("case,fault", [(c, f) for c, (cfg, _, chips)
+                                        in CASES.items()
+                                        for f in _faults(cfg, chips)])
 def test_fault_under_the_timed_path_is_not_correct(results, case, fault):
     assert results(case)[fault] is False
 
@@ -128,8 +154,9 @@ def test_harness_run_reports_correct_and_ends_with_the_checks(fault):
     drv = harness.driver(w["config"])
 
     def run():
-        return harness.run(w["name"], SEED, 0.2, False, require_tpu=False,
-                           traffic_override=SIZES[w["config"]][0])
+        with at_test_size(w["config"]) as tfc:
+            return harness.run(w["name"], SEED, 0.2, False,
+                               require_tpu=False, traffic_override=tfc)
     if fault is None:
         out = run()
     else:
